@@ -50,6 +50,10 @@ def main(argv=None):
     ap.add_argument("--out", default="bench_out.json")
     args = ap.parse_args(argv)
 
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from benchmarks import (allocator, cluster_density, concurrency,
                             dedup_store, density, forecast_density,
                             gateway_latency, governor_density,

@@ -131,10 +131,11 @@ class PagePool:
         of a per-page ``_set`` copy.
 
         ``use_kernel`` routes the copy through the ``page_copy.
-        scatter_pages`` Pallas kernel (the TPU path; CPU runs it in
-        interpret mode).  The kernel path rebinds ``self.data`` to the
-        kernel's output buffer, so it must only be enabled when no other
-        thread holds page views into the pool — the default numpy path is
+        scatter_pages`` Pallas kernel, compiled for the TPU (CPU callers
+        run it under ``pltpu.force_tpu_interpret_mode()``).  The kernel
+        path rebinds ``self.data`` to the kernel's output buffer, so it
+        must only be enabled when no other thread holds page views into
+        the pool — the default numpy path is
         an in-place vectorized store and is always safe."""
         rows = np.asarray(rows, self.dtype).reshape(len(pages),
                                                     self.page_elems)

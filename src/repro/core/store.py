@@ -48,7 +48,7 @@ from typing import (Callable, Dict, Hashable, List, Optional, Sequence,
 
 import numpy as np
 
-from repro.core.swap import WriteReceipt, read_extents
+from repro.core.swap import WriteReceipt, pwritev_full, read_extents
 
 
 @dataclass
@@ -248,7 +248,7 @@ class SwapStore:
         seg.level = level
         seg.crc = zlib.crc32(payload)
         seg.corrupt = False
-        os.pwrite(self.fd, payload, seg.offset)
+        pwritev_full(self.fd, [payload], seg.offset)
         self.bytes_written += len(payload)
         self.writes += 1
         self._release_extent(old_off, old_n)
@@ -370,7 +370,7 @@ class SwapStore:
                 seg = _Segment(self._alloc(len(payload)), len(payload),
                                len(buf), stored_level, refs=0,
                                tried_level=level, crc=zlib.crc32(payload))
-                os.pwrite(self.fd, payload, seg.offset)
+                pwritev_full(self.fd, [payload], seg.offset)
                 self.bytes_written += len(payload)
                 self.writes += 1
                 self._segments[digest] = seg
@@ -580,7 +580,7 @@ class SwapStore:
                 seg = _Segment(self._alloc(len(payload)), len(payload),
                                raw_nbytes, level, refs=0, tried_level=level,
                                imported_at=now, crc=zlib.crc32(payload))
-                os.pwrite(self.fd, payload, seg.offset)
+                pwritev_full(self.fd, [payload], seg.offset)
                 self.bytes_written += len(payload)
                 self.writes += 1
                 new.append(digest)

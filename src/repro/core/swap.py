@@ -56,6 +56,31 @@ def _preadv_full(fd, bufs, offset: int) -> int:
     return calls
 
 
+def pwritev_full(fd, bufs, offset: int) -> int:
+    """``pwritev`` that retries short writes (Linux caps one write at
+    ~2 GiB) until every buffer is on disk.  Returns the syscall count."""
+    views = [memoryview(b) for b in bufs]
+    want = sum(len(v) for v in views)
+    done, calls = 0, 0
+    while done < want:
+        pending, skip = [], done
+        for v in views:
+            if skip >= len(v):
+                skip -= len(v)
+                continue
+            pending.append(v[skip:] if skip else v)
+            skip = 0
+        if _HAVE_PWRITEV:
+            n = os.pwritev(fd, pending[:IOV_MAX], offset + done)
+        else:                              # pragma: no cover - non-POSIX
+            n = os.pwrite(fd, pending[0], offset + done)
+        calls += 1
+        if n <= 0:                         # pragma: no cover - IO error
+            raise OSError(f"pwritev: wrote nothing at offset {offset + done}")
+        done += n
+    return calls
+
+
 @dataclass
 class _Extent:
     offset: int
@@ -213,7 +238,7 @@ class SwapFile(_FileBase):
             self._append_at += len(buf)
         else:
             ext = _Extent(ext.offset, len(buf), str(arr.dtype), arr.shape)
-        os.pwrite(self.fd, buf, ext.offset)
+        pwritev_full(self.fd, [buf], ext.offset)
         self.extents[key] = ext
         self.bytes_written += len(buf)
         self.writes += 1
@@ -265,18 +290,7 @@ class ReapFile(_FileBase):
         tmp_fd = os.open(tmp, os.O_RDWR | os.O_CREAT | os.O_TRUNC, 0o600)
         try:
             if bufs:
-                if _HAVE_PWRITEV:
-                    pos, i = 0, 0
-                    while i < len(bufs):
-                        chunk = bufs[i:i + IOV_MAX]
-                        want = sum(len(b) for b in chunk)
-                        n = os.pwritev(tmp_fd, chunk, pos)
-                        if n != want:      # pragma: no cover - short write
-                            os.pwrite(tmp_fd, b"".join(chunk)[n:], pos + n)
-                        pos += want
-                        i += IOV_MAX
-                else:                      # pragma: no cover - non-POSIX
-                    os.pwrite(tmp_fd, b"".join(bufs), 0)
+                pwritev_full(tmp_fd, bufs, 0)
                 self.writes += 1
             os.rename(tmp, self.path)      # the commit point
         except BaseException:

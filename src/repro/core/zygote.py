@@ -166,6 +166,8 @@ class ZygotePool:
             if self.precompile is not None:
                 self.precompile(inst)
         except BaseException:
+            with mgr._lock:
+                mgr.instances.pop(zid, None)
             with self._lock:
                 ids = self._by_family.get(family, [])
                 if zid in ids:

@@ -1,4 +1,5 @@
-"""Pallas TPU kernels (validated with interpret=True on CPU).
+"""Pallas TPU kernels: compiled for the TPU by default; CPU tests ask for
+interpret mode and compare with the jnp oracles.
 
   page_copy       — batched page gather/scatter (the pwritev/preadv analogue)
   paged_attention — GQA decode over bitmap-allocated KV pages

@@ -38,7 +38,7 @@ def _scatter_kernel(idx_ref, buf_ref, pool_ref, out_ref):
 
 
 def gather_pages(pool: jax.Array, idx: jax.Array, *,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: bool = False) -> jax.Array:
     """pool: (P, R, 128); idx: (n,) int32 -> (n, R, 128)."""
     P, R, L = pool.shape
     assert L == LANE, f"last dim must be {LANE}"
@@ -60,7 +60,7 @@ def gather_pages(pool: jax.Array, idx: jax.Array, *,
 
 
 def scatter_pages(pool: jax.Array, idx: jax.Array, buf: jax.Array, *,
-                  interpret: bool = True) -> jax.Array:
+                  interpret: bool = False) -> jax.Array:
     """pool[idx[i]] = buf[i].  pool: (P, R, 128); buf: (n, R, 128).
 
     The pool is aliased in-place (donated) — the kernel only touches the

@@ -25,7 +25,7 @@ def as_pages(pool_flat: jax.Array) -> jax.Array:
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def gather_pages(pool: jax.Array, idx: jax.Array, *,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: bool = False) -> jax.Array:
     """pool: (P, R, 128) or (P, E); idx: (n,) -> (n, ...) page batch."""
     flat = pool.ndim == 2
     if flat:
@@ -38,7 +38,7 @@ def gather_pages(pool: jax.Array, idx: jax.Array, *,
 @functools.partial(jax.jit, static_argnames=("interpret",),
                    donate_argnames=("pool",))
 def scatter_pages(pool: jax.Array, idx: jax.Array, buf: jax.Array, *,
-                  interpret: bool = True) -> jax.Array:
+                  interpret: bool = False) -> jax.Array:
     flat = pool.ndim == 2
     if flat:
         P, E = pool.shape

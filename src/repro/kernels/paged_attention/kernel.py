@@ -81,7 +81,7 @@ def _decode_kernel(pt_ref, len_ref,            # scalar-prefetched
 
 def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
                            scale: float | None = None, window: int = 0,
-                           interpret: bool = True):
+                           interpret: bool = False):
     """q: (B, H, D); k_pages/v_pages: (Hkv, P, T, D);
     page_table: (B, pages_per_seq) int32 (entries past the sequence end may
     be any valid page id — they are masked); lengths: (B,) int32.

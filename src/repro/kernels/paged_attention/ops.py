@@ -13,7 +13,7 @@ from repro.kernels.paged_attention import kernel
                    static_argnames=("scale", "window", "interpret"))
 def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
                            scale=None, window: int = 0,
-                           interpret: bool = True):
+                           interpret: bool = False):
     """GQA decode over paged KV.  See kernel.py for shapes."""
     B, H, D = q.shape
     Hkv, P, T, Dk = k_pages.shape

@@ -16,7 +16,7 @@ from repro.kernels.ssd_scan import kernel
 
 @functools.partial(jax.jit, static_argnames=("chunk_size", "interpret"))
 def ssd(x, dt, A, Bm, Cm, D, *, chunk_size: int = 256, h0=None,
-        interpret: bool = True):
+        interpret: bool = False):
     """SSD forward.  x: (B,S,H,P); dt: (B,S,H); A,D: (H,); Bm,Cm: (B,S,N).
     Returns (y (B,S,H,P), h_final (B,H,N,P))."""
     B, S, H, P = x.shape
@@ -36,7 +36,7 @@ def ssd(x, dt, A, Bm, Cm, D, *, chunk_size: int = 256, h0=None,
     dtx = x.astype(jnp.float32) * dt32[..., None]          # (B,Sp,H,P)
 
     # chunk-major layouts
-    logdec = logdec.reshape(B, nc, Q, H).transpose(0, 3, 1, 2)
+    logdec = logdec.reshape(B, nc, Q, H).transpose(0, 3, 1, 2)[:, :, :, None]
     dtx = dtx.reshape(B, nc, Q, H, P).transpose(0, 3, 1, 2, 4)
     Bmc = Bm.reshape(B, nc, Q, N).astype(jnp.float32)
     Cmc = Cm.reshape(B, nc, Q, N).astype(jnp.float32)

@@ -129,15 +129,11 @@ def model_flops(cfg, shape) -> float:
 
 
 def xla_cost_dict(compiled) -> dict:
-    """Normalise ``compiled.cost_analysis()`` across jax versions: older
-    releases return a dict, newer ones a one-element list of dicts (one
-    per device), and either may be empty/None."""
+    """``compiled.cost_analysis()`` as a dict (empty when XLA has none)."""
     try:
         c = compiled.cost_analysis()
     except Exception:
         return {}
-    if isinstance(c, (list, tuple)):
-        c = c[0] if c else {}
     return c or {}
 
 

@@ -1,12 +1,15 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) case.
 
-The two lines above MUST precede any other import (jax locks the device
+The lines above MUST precede any other import (jax locks the device
 count at first init), which is why this module sets XLA_FLAGS before its
-docstring.  Do not import this module from tests or benchmarks — they are
-supposed to see one device.
+docstring.  The placeholders are CPU devices: a dry-run never takes an
+accelerator, so it is safe beside a process that holds the chip.  Do not
+import this module from tests or benchmarks — they are supposed to see
+one device.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch llama3.2-3b \

@@ -2,14 +2,22 @@
 
 Two modes:
   * ``--dry-run``: lower+compile serve_step (decode_32k) for the
-    production mesh via launch.dryrun.
-  * default: run a REAL trace on CPU (tiny configs): Poisson-ish arrivals
-    over N tenants served by the AsyncPlatform worker pool (bursts of
-    ``--burst`` requests run concurrently), keep-alive deflation, REAP or
-    pagefault wakes.  Reports per-state latency percentiles and final
-    memory per tenant.
+    production mesh via launch.dryrun (on the CPU, 512 placeholder devices).
+  * default: run a REAL trace on JAX's default device: Poisson-ish
+    arrivals over N tenants served by the AsyncPlatform worker pool
+    (bursts of ``--burst`` requests run concurrently), keep-alive
+    deflation, REAP or pagefault wakes.  Reports per-state latency
+    percentiles and final memory per tenant.  ``--scale`` picks the model
+    size: ``tiny``/``scaled`` are reduced float32 variants for the CPU,
+    ``full`` is the arch's published config in bf16.
 
   PYTHONPATH=src python -m repro.launch.serve --tenants 4 --requests 24
+  PYTHONPATH=src python -m repro.launch.serve --scale full --tenants 1 \
+      --requests 4 --workers 1
+
+Until WARM weights live on the device, every dispatch uploads the
+tenant's whole model, so at ``--scale full`` each parallel worker holds
+its own copy in device memory: keep ``--workers 1`` on one chip.
 """
 from __future__ import annotations
 
@@ -22,7 +30,9 @@ import time
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="llama3.2-3b")
+    ap.add_argument("--arch", default="phi4-mini-3.8b")
+    ap.add_argument("--scale", choices=("tiny", "scaled", "full"),
+                    default="tiny")
     ap.add_argument("--tenants", type=int, default=3)
     ap.add_argument("--requests", type=int, default=18)
     ap.add_argument("--wake-mode", choices=("reap", "pagefault"),
@@ -46,7 +56,11 @@ def main(argv=None):
     import numpy as np
     import jax
 
-    from repro.configs import get_config, tiny_config
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
+    from repro.configs import get_config, scaled_config, tiny_config
     from repro.core.manager import InstanceManager, ManagerConfig
     from repro.core.metrics import memory_report
     from repro.models import model
@@ -55,9 +69,16 @@ def main(argv=None):
 
     shutil.rmtree(args.spool, ignore_errors=True)
 
+    scale_cfg = {"tiny": tiny_config, "scaled": scaled_config,
+                 "full": lambda cfg: cfg}[args.scale]
+
+    init = jax.jit(model.init_params, static_argnums=1)
+
     def factory(arch):
-        cfg = tiny_config(get_config(arch))
-        return cfg, model.init_params(jax.random.PRNGKey(0), cfg)
+        # no params cache: the instance copies them to the host, and a
+        # kept device copy would double the model's device footprint
+        cfg = scale_cfg(get_config(arch))
+        return cfg, init(jax.random.PRNGKey(args.seed), cfg)
 
     mgr = InstanceManager(
         ManagerConfig(spool_dir=args.spool, wake_mode=args.wake_mode),

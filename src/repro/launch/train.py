@@ -41,6 +41,10 @@ def main(argv=None):
     import jax
     import jax.numpy as jnp
 
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from repro.configs import get_config, scaled_config, tiny_config
     from repro.data import DataConfig, SyntheticPipeline
     from repro.models import model

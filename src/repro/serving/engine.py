@@ -212,22 +212,21 @@ class ServingEngine:
         """Pre-build the prefill executables for a zygote — the cold-start
         cost a fork skips.  Each configured prompt length is compiled by
         an actual dummy dispatch (jit tracing alone would defer the XLA
-        compile to the first real request); lengths that cannot run on
-        dummy inputs (frontend archs wanting embeds/frames) are skipped —
-        the fork still wins on init, just not on compile."""
+        compile to the first real request).  Frontend archs (wanting
+        embeds/frames) cannot run on dummy tokens and are skipped — the
+        fork still wins on init, just not on compile.  Any other failure
+        (a refused compile, device OOM) raises."""
+        cfg = inst.cfg
+        if cfg.frontend.kind != "none" or cfg.is_encoder_decoder:
+            return
         zp = self.manager.zygotes
         lens = zp.cfg.precompile_prompt_lens if zp is not None else (8,)
         params = inst.params_pytree()
         for L in lens:
-            try:
-                fn = self._compiled(inst, "prefill", 1, int(L),
-                                    False, False)
-                logits, _, _ = fn(params,
-                                  jnp.zeros((1, int(L)), jnp.int32),
-                                  None, None)
-                jax.block_until_ready(logits)
-            except Exception:
-                continue
+            fn = self._compiled(inst, "prefill", 1, int(L), False, False)
+            logits, _, _ = fn(params, jnp.zeros((1, int(L)), jnp.int32),
+                              None, None)
+            jax.block_until_ready(logits)
 
     def _compiled(self, inst: ModelInstance, kind: str, B: int, Sb: int,
                   has_embeds: bool, has_frames: bool):

@@ -72,7 +72,7 @@ def kernel_view(kv: PagedKVCache, session_ids: Sequence[str], layer: int):
 
 
 def paged_decode(kv: PagedKVCache, session_ids: Sequence[str], layer: int,
-                 q, *, window: int = 0, interpret: bool = True):
+                 q, *, window: int = 0, interpret: bool = False):
     """q: (B, H, D) query for one layer -> (B, H, D) attention output,
     computed by the Pallas kernel directly over pool pages."""
     k_pages, v_pages, page_table, lengths = kernel_view(

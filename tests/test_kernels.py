@@ -1,4 +1,5 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps, interpret=True."""
+"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps.  The CPU has no
+Mosaic backend, so every call asks for interpret mode itself."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -25,7 +26,7 @@ def _tol(dtype):
 def test_page_gather(P, R, n, dtype):
     pool = jnp.asarray(RNG.integers(-100, 100, (P, R, 128)), dtype)
     idx = jnp.asarray(RNG.integers(0, P, (n,)), jnp.int32)
-    out = pc_ops.gather_pages(pool, idx)
+    out = pc_ops.gather_pages(pool, idx, interpret=True)
     np.testing.assert_array_equal(np.asarray(out),
                                   np.asarray(pc_ref.gather_pages(pool, idx)))
 
@@ -37,7 +38,7 @@ def test_page_scatter(P, R, n, dtype):
     idx = jnp.asarray(RNG.choice(P, n, replace=False), jnp.int32)
     buf = jnp.asarray(RNG.standard_normal((n, R, 128)), dtype)
     expect = pc_ref.scatter_pages(pool, idx, buf)
-    out = pc_ops.scatter_pages(pool, idx, buf)
+    out = pc_ops.scatter_pages(pool, idx, buf, interpret=True)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(expect))
 
 
@@ -45,8 +46,8 @@ def test_page_roundtrip_flat():
     pool = jnp.asarray(RNG.standard_normal((32, 512)), jnp.float32)
     expect = np.asarray(pool)                 # scatter donates the pool
     idx = jnp.asarray([3, 9, 27], jnp.int32)
-    buf = pc_ops.gather_pages(pool, idx)
-    out = pc_ops.scatter_pages(pool, idx, buf)       # scatter back = identity
+    buf = pc_ops.gather_pages(pool, idx, interpret=True)
+    out = pc_ops.scatter_pages(pool, idx, buf, interpret=True)       # scatter back = identity
     np.testing.assert_array_equal(np.asarray(out), expect)
 
 
@@ -68,7 +69,8 @@ def test_paged_attention_sweep(B, H, Hkv, T, pps, dtype):
     vp = jnp.asarray(RNG.standard_normal((Hkv, P, T, D)), dtype)
     pt = jnp.asarray(RNG.integers(0, P, (B, pps)), jnp.int32)
     lengths = jnp.asarray(RNG.integers(1, pps * T + 1, (B,)), jnp.int32)
-    out = pa_ops.paged_decode_attention(q, kp, vp, pt, lengths)
+    out = pa_ops.paged_decode_attention(q, kp, vp, pt, lengths,
+                                        interpret=True)
     exp = pa_ref.paged_decode_attention(q, kp, vp, pt, lengths)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(exp, np.float32), **_tol(dtype))
@@ -83,7 +85,7 @@ def test_paged_attention_window(window):
     pt = jnp.asarray(RNG.integers(0, P, (B, pps)), jnp.int32)
     lengths = jnp.asarray([5, 30], jnp.int32)
     out = pa_ops.paged_decode_attention(q, kp, vp, pt, lengths,
-                                        window=window)
+                                        window=window, interpret=True)
     exp = pa_ref.paged_decode_attention(q, kp, vp, pt, lengths,
                                         window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
@@ -106,7 +108,8 @@ def test_paged_attention_matches_dense_decode():
     v_d = vp[:, pt].transpose(1, 2, 3, 0, 4).reshape(B, S, Hkv, D)
     pos = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
     dense = decode_attention(q, k_d, v_d, pos, lengths)
-    paged = pa_ops.paged_decode_attention(q, kp, vp, pt, lengths)
+    paged = pa_ops.paged_decode_attention(q, kp, vp, pt, lengths,
+                                          interpret=True)
     np.testing.assert_allclose(np.asarray(paged), np.asarray(dense),
                                rtol=2e-5, atol=2e-5)
 
@@ -129,7 +132,8 @@ def test_ssd_scan_sweep(B, S, H, P, N, Q, dtype):
     Bm = jnp.asarray(RNG.standard_normal((B, S, N)) * 0.3, dtype)
     Cm = jnp.asarray(RNG.standard_normal((B, S, N)) * 0.3, dtype)
     D = jnp.asarray(RNG.standard_normal((H,)), jnp.float32)
-    y, h = ssd_ops.ssd(x, dt, A, Bm, Cm, D, chunk_size=Q)
+    y, h = ssd_ops.ssd(x, dt, A, Bm, Cm, D, chunk_size=Q,
+                       interpret=True)
     ye, he = ssd_ref.ssd(x, dt, A, Bm, Cm, D, chunk_size=Q)
     tol = _tol(dtype)
     np.testing.assert_allclose(np.asarray(y, np.float32),
@@ -146,11 +150,12 @@ def test_ssd_scan_state_chaining():
     Bm = jnp.asarray(RNG.standard_normal((B, S, N)) * 0.3, jnp.float32)
     Cm = jnp.asarray(RNG.standard_normal((B, S, N)) * 0.3, jnp.float32)
     D = jnp.zeros((H,), jnp.float32)
-    y_full, h_full = ssd_ops.ssd(x, dt, A, Bm, Cm, D, chunk_size=Q)
+    y_full, h_full = ssd_ops.ssd(x, dt, A, Bm, Cm, D, chunk_size=Q,
+                       interpret=True)
     y1, h1 = ssd_ops.ssd(x[:, :32], dt[:, :32], A, Bm[:, :32], Cm[:, :32],
-                         D, chunk_size=Q)
+                         D, chunk_size=Q, interpret=True)
     y2, h2 = ssd_ops.ssd(x[:, 32:], dt[:, 32:], A, Bm[:, 32:], Cm[:, 32:],
-                         D, chunk_size=Q, h0=h1)
+                         D, chunk_size=Q, h0=h1, interpret=True)
     np.testing.assert_allclose(np.asarray(jnp.concatenate([y1, y2], 1)),
                                np.asarray(y_full), rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(h2), np.asarray(h_full),
@@ -168,7 +173,8 @@ def test_ssd_kernel_matches_model_block():
     Bm = jnp.asarray(RNG.standard_normal((B, S, N)), jnp.float32)
     Cm = jnp.asarray(RNG.standard_normal((B, S, N)), jnp.float32)
     D = jnp.asarray(RNG.standard_normal((H,)), jnp.float32)
-    y_k, h_k = ssd_ops.ssd(x, dt, A, Bm, Cm, D, chunk_size=Q)
+    y_k, h_k = ssd_ops.ssd(x, dt, A, Bm, Cm, D, chunk_size=Q,
+                       interpret=True)
     y_m, h_m = ssd_chunked(x, dt, A, Bm, Cm, D, chunk_size=Q)
     np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_m),
                                rtol=1e-4, atol=1e-4)

@@ -52,7 +52,7 @@ def test_kernel_matches_dense_on_pool(served_instance):
     q = jnp.asarray(rng.standard_normal(
         (3, cfg.num_heads, cfg.head_dim)), jnp.float32)
     for layer in (0, cfg.num_layers - 1):
-        out = paged_decode(inst.kv, sids, layer, q)
+        out = paged_decode(inst.kv, sids, layer, q, interpret=True)
         ref = _dense_reference(inst, sids, layer, q)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
@@ -66,10 +66,10 @@ def test_kernel_survives_hibernation(served_instance):
     cfg = inst.cfg
     q = jnp.asarray(np.random.default_rng(1).standard_normal(
         (3, cfg.num_heads, cfg.head_dim)), jnp.float32)
-    before = paged_decode(inst.kv, sids, 0, q)
+    before = paged_decode(inst.kv, sids, 0, q, interpret=True)
     mgr.descend("i0", Rung.HIBERNATED)
     keys = [k for s in sids for k in inst.kv.keys_for(s)]
     mgr.hib.fault(inst, inst.kv.nonresident_keys(keys))
-    after = paged_decode(inst.kv, sids, 0, q)
+    after = paged_decode(inst.kv, sids, 0, q, interpret=True)
     np.testing.assert_allclose(np.asarray(after), np.asarray(before),
                                rtol=1e-6, atol=1e-6)

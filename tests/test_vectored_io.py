@@ -71,6 +71,42 @@ def test_vectored_read_unsorted_keys(spool_dir):
     f.delete()
 
 
+@pytest.mark.parametrize("tier", ["reap", "swap", "store"])
+def test_short_writes_are_retried(spool_dir, monkeypatch, tier):
+    """Linux writes at most ~2 GiB per call: every tier must loop until
+    the whole unit is on disk (here each call is capped at 100 bytes)."""
+    from repro.core.store import SwapStore
+
+    real_v, real = os.pwritev, os.pwrite
+
+    def capped_v(fd, bufs, offset):
+        head, left = [], 100
+        for b in bufs:
+            if not left:
+                break
+            head.append(memoryview(b)[:left])
+            left -= len(head[-1])
+        return real_v(fd, head, offset)
+
+    monkeypatch.setattr(os, "pwritev", capped_v)
+    monkeypatch.setattr(os, "pwrite",
+                        lambda fd, b, offset: real(fd, b[:100], offset))
+    items = _units(8, seed=6)
+    if tier == "reap":
+        f = ReapFile(f"{spool_dir}/s.reap")
+        f.write_batch(items)
+    elif tier == "swap":
+        f = SwapFile(f"{spool_dir}/s.swap")
+        f.write_units(items)
+    else:
+        store = SwapStore(f"{spool_dir}/s.cas", salt=b"short-writes")
+        f = store.client("t")
+        f.write_units(items)
+    out = f.read_units([k for k, _ in items])
+    for k, a in items:
+        np.testing.assert_array_equal(out[k], a)
+
+
 def test_reap_shrinking_rewrite_truncates(spool_dir):
     """A smaller rewrite must not leave stale trailing bytes on disk:
     file_bytes tracks the real footprint the memory benchmarks report."""

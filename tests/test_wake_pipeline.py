@@ -288,7 +288,16 @@ def test_store_client_streaming_iter(tiny_factory, spool_dir):
         np.testing.assert_array_equal(seen[k], whole[k])
 
 
-def test_pool_scatter_kernel_matches_numpy():
+@pytest.fixture()
+def tpu_interpret():
+    """The CPU has no Mosaic backend: run the pool's Pallas kernel in
+    interpret mode."""
+    from jax.experimental.pallas import tpu as pltpu
+    with pltpu.force_tpu_interpret_mode():
+        yield
+
+
+def test_pool_scatter_kernel_matches_numpy(tpu_interpret):
     pool = PagePool(256, np.float32, capacity_pages=64)
     pages = pool.alloc(8, "t0")
     rng = np.random.default_rng(1)

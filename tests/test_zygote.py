@@ -105,6 +105,29 @@ def test_fork_without_pool_or_donor_falls_back(tiny_factory, spool_dir):
     assert mgr2.fork_start("t", ARCH) is None     # pool, but no donor
 
 
+@pytest.mark.parametrize("arch,dispatches", [
+    (ARCH, True), ("llava-next-34b", False), ("whisper-large-v3", False)])
+def test_spawn_precompile_raises_or_skips_frontends(tiny_factory, spool_dir,
+                                                    monkeypatch, arch,
+                                                    dispatches):
+    """A donor whose prefill cannot be built fails its spawn (and frees
+    its slot) instead of hiding the fault; frontend archs, which cannot
+    run on dummy tokens, are skipped without a dispatch."""
+    mgr = _mgr(tiny_factory, spool_dir, shared=False)
+    eng = ServingEngine(mgr)
+
+    def refused(*a):
+        raise RuntimeError("compile refused")
+
+    monkeypatch.setattr(eng, "_compiled", lambda *a: refused)
+    if dispatches:
+        with pytest.raises(RuntimeError, match="compile refused"):
+            mgr.zygotes.spawn(arch)
+        assert not mgr.instances and not mgr.zygotes._by_family[arch]
+    else:
+        assert mgr.zygotes.spawn(arch).state is S.ZYGOTE
+
+
 def test_platform_admits_unknown_tenant_by_fork(tiny_factory, spool_dir):
     """The serve path tries the fork first: an unknown tenant's first
     request rides a live donor (logged ``fork_start``), and only a
