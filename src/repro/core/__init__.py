@@ -6,8 +6,7 @@ from repro.core.hibernate import DeflateStats, HibernationManager, WakeStats
 from repro.core.instance import EMBED_BLOCK, ModelInstance, WeightUnit
 from repro.core.manager import (InstanceManager, ManagerConfig,
                                 SharedWeightsRegistry)
-from repro.core.metrics import (LatencyTrace, MemoryReport, memory_report,
-                                per_rung_report)
+from repro.core.metrics import MemoryReport, memory_report, per_rung_report
 from repro.core.pool import PagePool
 from repro.core.reap import ReapRecorder
 from repro.core.state import (DEFLATED_STATES, PAUSED_STATES, RUNG_OF,

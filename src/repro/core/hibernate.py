@@ -60,6 +60,7 @@ from typing import List, Optional, Tuple
 from repro.core.inflate import (InflatePipeline, InflatorPool,
                                 partial_restore_keys)
 from repro.core.instance import ModelInstance
+from repro.core.metrics import span
 from repro.core.state import ContainerState, Event
 
 
@@ -357,13 +358,16 @@ class HibernationManager:
                 pipe.wait_critical()
             else:
                 # ONE batched sequential read (preadv), -> weights + KV
+                tenant = inst.instance_id
                 t_io = time.monotonic()
-                data = inst.reap_file.read_batch()
+                with span("wake.read", tenant=tenant):
+                    data = inst.reap_file.read_batch()
                 st.io_seconds = time.monotonic() - t_io
                 t_inf = time.monotonic()
-                st.prefetched_bytes += inst.apply_prefetch(data)
-                if inst.kv is not None:
-                    st.prefetched_bytes += inst.kv.apply_prefetch(data)
+                with span("wake.install", tenant=tenant):
+                    st.prefetched_bytes += inst.apply_prefetch(data)
+                    if inst.kv is not None:
+                        st.prefetched_bytes += inst.kv.apply_prefetch(data)
                 st.inflate_seconds = time.monotonic() - t_inf
         # pagefault mode restores nothing here; units fault in on access
 

@@ -42,6 +42,7 @@ from typing import Dict, Hashable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.core.metrics import span
 from repro.core.swap import read_extents
 
 #: chunk states
@@ -268,8 +269,10 @@ class InflatePipeline:
         offsets — the REAP file is laid out in stream order, so a chunk is
         a handful of merged sequential runs)."""
         t0 = time.monotonic()
-        bufs, calls = read_extents(self.inst.reap_file.fd,
-                                   [(off, n) for off, n, _, _ in chunk.extents])
+        with span("wake.read", tenant=self.inst.instance_id):
+            bufs, calls = read_extents(
+                self.inst.reap_file.fd,
+                [(off, n) for off, n, _, _ in chunk.extents])
         dt = time.monotonic() - t0
         with self._cv:
             self.stats.io_seconds += dt
@@ -282,11 +285,12 @@ class InflatePipeline:
         """Stages 2+3: materialize arrays and install them (weights via
         ``_set_unit``, KV pages batched through one pool scatter)."""
         t0 = time.monotonic()
-        data: Dict[Hashable, np.ndarray] = {}
-        for key, (_, _, dtype, shape), buf in zip(chunk.keys, chunk.extents,
-                                                  bufs):
-            data[key] = np.frombuffer(buf, dtype).reshape(shape)
-        installed = self.inst.install_units(data)
+        with span("wake.install", tenant=self.inst.instance_id):
+            data: Dict[Hashable, np.ndarray] = {}
+            for key, (_, _, dtype, shape), buf in zip(
+                    chunk.keys, chunk.extents, bufs):
+                data[key] = np.frombuffer(buf, dtype).reshape(shape)
+            installed = self.inst.install_units(data)
         with self._cv:
             self.stats.inflate_seconds += time.monotonic() - t0
             self.stats.prefetched_bytes += installed

@@ -15,7 +15,6 @@ instance table is lock-guarded and each instance has a wake lock.
 from __future__ import annotations
 
 import threading
-import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
 
@@ -25,6 +24,7 @@ from repro.core.governor import GovernorConfig, MemoryGovernor
 from repro.core.hibernate import HibernationManager
 from repro.core.inflate import InflatorPool
 from repro.core.instance import ModelInstance
+from repro.core.metrics import span
 from repro.core.pool import PagePool
 from repro.core.state import (DEFLATE_EVENT_FOR, ContainerState, Event,
                               Rung)
@@ -184,7 +184,6 @@ class InstanceManager:
         self.governor = MemoryGovernor(
             self, budget_bytes=cfg.memory_budget_bytes,
             cfg=cfg.governor_policy)
-        self.events: List[tuple] = []
         self._lock = threading.RLock()                 # instance table
         self._wake_locks: Dict[str, threading.Lock] = {}
         #: tenants migrated off this node -> target node id, so straggler
@@ -243,7 +242,6 @@ class InstanceManager:
             # a cold start IS a new-tenant admission the pool missed —
             # it trains the same fork-avoidance signal a fork does
             self.zygotes.note_admission(arch_key)
-        self.events.append((time.monotonic(), "cold_start", instance_id))
         return inst
 
     def fork_start(self, instance_id: str, arch_key: str,
@@ -292,8 +290,6 @@ class InstanceManager:
             self.zygotes.note_admission(arch_key)
             self.zygotes.forked += 1
             self.forks_performed += 1
-            self.events.append((time.monotonic(), "fork", instance_id,
-                                zyg.instance_id))
             return inst
 
     def _consume_zygote(self, zyg: ModelInstance) -> None:
@@ -310,7 +306,6 @@ class InstanceManager:
         self.governor.forget(zid)
         if self.zygotes is not None:
             self.zygotes.note_evicted(zid)
-        self.events.append((time.monotonic(), "zygote_consumed", zid))
 
     def descend(self, instance_id: str, rung, *, keys=None):
         """Walk one tenant down the deflation ladder to ``rung``.
@@ -337,18 +332,21 @@ class InstanceManager:
         if rung not in DEFLATE_EVENT_FOR:
             raise ValueError(f"{rung!r} is not a deflation target")
         inst = self.instances[instance_id]
-        if rung == Rung.TERMINATED:
-            self.evict(instance_id)
-            return None
-        if rung == Rung.MMAP_CLEAN:
-            st = self.hib.deflate_mmap(inst)
-        elif rung == Rung.PARTIAL:
-            if keys is None:
-                keys = [k for _, _, k in
-                        self.governor._partial_candidates(inst)]
-            st = self.hib.deflate_partial(inst, keys)
-        else:
-            st = self.hib.deflate(inst)
+        # no request owns a deflate: the span is a trace annotation only
+        with span("ladder.deflate", tenant=instance_id,
+                  rung=rung.name.lower()):
+            if rung == Rung.TERMINATED:
+                self.evict(instance_id)
+                return None
+            if rung == Rung.MMAP_CLEAN:
+                st = self.hib.deflate_mmap(inst)
+            elif rung == Rung.PARTIAL:
+                if keys is None:
+                    keys = [k for _, _, k in
+                            self.governor._partial_candidates(inst)]
+                st = self.hib.deflate_partial(inst, keys)
+            else:
+                st = self.hib.deflate(inst)
         # every descent path (governor pressure, keep-alive, router)
         # accumulates the tenant's wake footprint — what a pre-inflate
         # or the elasticity demand model expects the wake to re-occupy;
@@ -444,7 +442,6 @@ class InstanceManager:
         self.governor.forget(instance_id)
         if self.on_evict is not None:
             self.on_evict(instance_id)
-        self.events.append((time.monotonic(), "migrate_out", instance_id))
 
     def admit(self, inst: ModelInstance) -> None:
         """Migration commit on the *target* node: install a rebuilt
@@ -453,8 +450,6 @@ class InstanceManager:
         with self._lock:
             self.instances[inst.instance_id] = inst
             self.migrated.pop(inst.instance_id, None)
-        self.events.append((time.monotonic(), "migrate_in",
-                            inst.instance_id))
 
     def evict(self, instance_id: str) -> None:
         """TERMINATED: destroy the container — release its shared mmap
@@ -478,7 +473,6 @@ class InstanceManager:
             self.zygotes.note_evicted(instance_id)
         if self.on_evict is not None:
             self.on_evict(instance_id)
-        self.events.append((time.monotonic(), "evict", instance_id))
 
     # ------------------------------------------------------------- policy
     def resident_bytes(self) -> int:
@@ -523,7 +517,6 @@ class InstanceManager:
         actions = self.governor.step(now=now, try_lock=try_lock,
                                      budget_bytes=target_bytes)
         acted = list(dict.fromkeys(a.instance_id for a in actions))
-        self.events.append((time.monotonic(), "pressure", tuple(acted)))
         return acted
 
     def states(self) -> Dict[str, str]:

@@ -1,16 +1,18 @@
 """Memory accounting (PSS analogue of the paper's `pmap` methodology) and
-latency tracing for the per-state benchmarks (Figs. 6/7).
+the serving path's one tracing helper, :class:`span`.
 
-:class:`LatencyTrace` is thread-safe: the AsyncPlatform's worker pool
-records spans concurrently from many serving threads.
+A span is a ``jax.profiler.TraceAnnotation`` (on the profiler's clock,
+on the thread that ran it, with ids such as ``tenant`` and ``batch`` as
+event stats) whose duration is also added to ``Response.spans[name]`` of
+each response it serves.  A span that repeats (a decode step) adds up.
 """
 from __future__ import annotations
 
-import threading
 import time
-from contextlib import contextmanager
-from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence
+from dataclasses import dataclass
+from typing import Dict, Sequence
+
+from jax.profiler import TraceAnnotation
 
 
 def percentile(xs: Sequence[float], q: float) -> float:
@@ -132,36 +134,28 @@ def cluster_report(nodes) -> Dict[str, Dict[str, float]]:
     return out
 
 
-class LatencyTrace:
-    """Named wall-clock spans, e.g. cold_start / prefill / decode / wake."""
+def charge(resps: Sequence, name: str, seconds: float) -> None:
+    """Add ``seconds`` to ``spans[name]`` of each response."""
+    for r in resps:
+        r.spans[name] = r.spans.get(name, 0.0) + seconds
 
-    def __init__(self):
-        self.spans: Dict[str, List[float]] = {}
-        self._lock = threading.Lock()
 
-    @contextmanager
-    def span(self, name: str):
-        t0 = time.monotonic()
-        try:
-            yield
-        finally:
-            dt = time.monotonic() - t0
-            with self._lock:
-                self.spans.setdefault(name, []).append(dt)
+class span:
+    """Trace the enclosed work as ``name`` and charge its wall time to
+    ``resps``.  ``ids`` (str or int) become the annotation's stats.  With
+    no profiler running the annotation costs about a microsecond."""
 
-    def total(self, name: str) -> float:
-        return sum(self.spans.get(name, ()))
+    __slots__ = ("name", "resps", "_note", "_t0")
 
-    def mean(self, name: str) -> Optional[float]:
-        xs = self.spans.get(name)
-        return sum(xs) / len(xs) if xs else None
+    def __init__(self, name: str, resps: Sequence = (), **ids):
+        self.name, self.resps = name, resps
+        self._note = TraceAnnotation(name, **ids)
 
-    def p(self, name: str, q: float) -> float:
-        """Percentile over a span's samples (e.g. ``p("e2e", 99)``)."""
-        with self._lock:
-            xs = list(self.spans.get(name, ()))
-        return percentile(xs, q)
+    def __enter__(self) -> "span":
+        self._note.__enter__()
+        self._t0 = time.monotonic()
+        return self
 
-    def summary(self) -> Dict[str, float]:
-        with self._lock:
-            return {k: sum(v) / len(v) for k, v in self.spans.items()}
+    def __exit__(self, *exc) -> None:
+        charge(self.resps, self.name, time.monotonic() - self._t0)
+        self._note.__exit__(*exc)

@@ -175,7 +175,6 @@ class ZygotePool:
                 self._spawned_at.pop(zid, None)
             raise
         self.spawned += 1
-        mgr.events.append((time.monotonic(), "zygote_spawn", zid))
         return inst
 
     def ensure(self, family: str, shared_paths=None

@@ -6,7 +6,10 @@ Two modes:
   * default: run a REAL trace on JAX's default device: Poisson-ish
     arrivals over N tenants served by the AsyncPlatform worker pool
     (bursts of ``--burst`` requests run concurrently), keep-alive
-    deflation, REAP or pagefault wakes.  Reports per-state latency
+    deflation, REAP or pagefault wakes.  Prints each response's
+    breakdown from its spans (queue, serve-lock wait, wake, prefill,
+    decode, KV writeback, in ms) beside its end-to-end time, with the
+    host bytes its compiled steps uploaded; then per-state latency
     percentiles and final memory per tenant.  ``--scale`` picks the model
     size: ``tiny``/``scaled`` are reduced float32 variants for the CPU,
     ``full`` is the arch's published config in bf16.
@@ -26,6 +29,18 @@ import shutil
 import subprocess
 import sys
 import time
+
+#: ``Response.spans`` keys printed per response, under their column names
+BREAKDOWN = (("queue", "serve.queue"), ("lock", "serve.lock_wait"),
+             ("wake", "wake"), ("prefill", "serve.prefill"),
+             ("decode", "serve.decode"), ("writeback", "kv.writeback"))
+
+
+def breakdown(resp) -> str:
+    """One response's spans in ms and its host-to-device bytes."""
+    parts = [f"{col}={resp.spans.get(key, 0.0) * 1e3:.0f}"
+             for col, key in BREAKDOWN]
+    return " ".join(parts) + f" h2d={resp.h2d_bytes / 1e6:.1f}MB"
 
 
 def main(argv=None):
@@ -109,7 +124,8 @@ def main(argv=None):
                 print(f"  req{r_i:03d} {tenant:5s} {resp.state_before:9s}->"
                       f"{resp.state_after:6s} "
                       f"{resp.spans['e2e'] * 1e3:7.0f}ms "
-                      f"faults={resp.faults}", flush=True)
+                      f"[{breakdown(resp)}] faults={resp.faults}",
+                      flush=True)
             for iid in plat.policy_pass():
                 print(f"    [policy] deflated {iid}")
             # REAP-record each tenant once it has served

@@ -24,11 +24,24 @@ whole request, so the AsyncPlatform's worker pool can serve *different*
 instances in parallel while each instance's state machine stays
 race-free.  Wakes route through ``InstanceManager.ensure_awake`` so a
 wake storm on one hibernating tenant performs exactly one inflate.
+
+Tracing: every layer boundary of a batch is a
+:class:`~repro.core.metrics.span` (``serve.lock_wait``, ``wake``,
+``serve.prefill`` with
+``prefill.fault|dispatch``, ``kv.write`` and ``prefix.register``,
+``serve.decode`` with ``decode.fault``, ``kv.gather``, ``decode.step`` >
+``decode.dispatch`` and ``kv.writeback``), so each response carries its
+own breakdown in ``Response.spans`` and the profiler sees the same spans
+with ``tenant``/``batch`` (and per request ``session``) stats.  The
+counters beside them (``h2d_bytes``, ``decode_steps``,
+``dispatch_inflight``, ``compiles``) are charged at the same boundaries.
 """
 from __future__ import annotations
 
+import itertools
 import threading
 import time
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -38,7 +51,7 @@ import numpy as np
 
 from repro.core.instance import ModelInstance
 from repro.core.manager import InstanceManager
-from repro.core.metrics import LatencyTrace
+from repro.core.metrics import charge, span
 from repro.core.state import ContainerState, Event
 from repro.models import model
 from repro.serving.paged_kv import PagedKVCache
@@ -119,6 +132,75 @@ class Response:
     #: True when prefill was skipped entirely: the prompt's KV pages were
     #: COW-adopted from the deployment prefix registry
     adopted_prefix: bool = False
+    #: the ``serve_batch`` call that served it (the ``batch`` stat of its
+    #: trace events).  Batch-level spans and the counters below marked
+    #: "batch" read the same on every response of one batch.
+    batch: int = -1
+    #: bytes of host (numpy) arrays handed to compiled steps: this
+    #: request's prefill plus its batch's decode steps
+    h2d_bytes: int = 0
+    #: decode steps its batch ran (batch)
+    decode_steps: int = 0
+    #: mean over the batch's dispatches of the compiled steps in flight in
+    #: the process when each started, itself included (batch)
+    dispatch_inflight: float = 0.0
+    #: backend compiles on the serving thread while the batch ran (batch)
+    compiles: int = 0
+
+
+@dataclass
+class _Batch:
+    """One ``serve_batch`` call: the responses its spans and counters are
+    charged to, and the in-flight count at each of its dispatches."""
+
+    tenant: str
+    id: int
+    resps: List[Response]
+    inflight: List[int] = field(default_factory=list)
+
+    def ids(self, req: Optional[Request] = None) -> Dict[str, object]:
+        """Stats of its trace events; a request's own carry its session."""
+        ids = {"tenant": self.tenant, "batch": self.id}
+        if req is not None:
+            ids["session"] = req.session_id
+        return ids
+
+
+# ---------------------------------------------------------------------------
+# compile accounting
+# ---------------------------------------------------------------------------
+
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+#: the batch each serving thread has open (``batch``), for compile charges
+_open = threading.local()
+_compile_listener_guard = threading.Lock()
+_compile_listener_on = False
+
+
+def _charge_compile(event: str, secs: float, **_) -> None:
+    """Charge a backend compile to the batch open on the compiling thread;
+    compiles outside any batch (a warm-up) are nobody's."""
+    batch = getattr(_open, "batch", None)
+    if event == _BACKEND_COMPILE and batch is not None:
+        for r in batch.resps:
+            r.compiles += 1
+
+
+def _listen_for_compiles() -> None:
+    """Register :func:`_charge_compile` once per process."""
+    global _compile_listener_on
+    with _compile_listener_guard:
+        if not _compile_listener_on:
+            jax.monitoring.register_event_duration_secs_listener(
+                _charge_compile)
+            _compile_listener_on = True
+
+
+def _host_bytes(tree) -> int:
+    """Bytes of the host (numpy) arrays in a pytree: what a compiled step
+    copies to the device when it is called with them."""
+    return sum(x.nbytes for x in jax.tree.leaves(tree)
+               if isinstance(x, np.ndarray))
 
 
 # ---------------------------------------------------------------------------
@@ -155,9 +237,14 @@ class ServingEngine:
         self.manager = manager
         self.window = window
         self.max_new_default = max_new_default
-        self.trace = LatencyTrace()
         self._locks: Dict[str, threading.RLock] = {}
         self._locks_guard = threading.Lock()
+        self._batch_ids = itertools.count()
+        #: compiled steps between their call and the first read of their
+        #: result, over every tenant and worker thread
+        self._inflight = 0
+        self._inflight_lock = threading.Lock()
+        _listen_for_compiles()
         # the zygote pool compiles through the engine: spawned donors get
         # their prefill executables pre-built so a fork inherits them
         zp = manager.zygotes
@@ -184,11 +271,10 @@ class ServingEngine:
     def start_instance(self, instance_id: str, arch_key: str,
                        shared_paths=None) -> ModelInstance:
         """Cold start (①): init/load + attach the paged cache."""
-        with self.trace.span("cold_start"):
-            inst = self.manager.cold_start(instance_id, arch_key,
-                                           shared_paths=shared_paths)
-            inst.kv = PagedKVCache(instance_id, inst.cfg, self.manager.pool,
-                                   registry=self.manager.prefix_registry)
+        inst = self.manager.cold_start(instance_id, arch_key,
+                                       shared_paths=shared_paths)
+        inst.kv = PagedKVCache(instance_id, inst.cfg, self.manager.pool,
+                               registry=self.manager.prefix_registry)
         return inst
 
     def fork_instance(self, instance_id: str, arch_key: str,
@@ -199,13 +285,11 @@ class ServingEngine:
         None when no zygote is available — callers fall back to
         ``start_instance``.  A concurrent fork of the same tenant dedups
         below (the returned instance may already carry a cache)."""
-        with self.trace.span("fork_start"):
-            inst = self.manager.fork_start(instance_id, arch_key,
-                                           shared_paths=shared_paths)
-            if inst is not None and inst.kv is None:
-                inst.kv = PagedKVCache(instance_id, inst.cfg,
-                                       self.manager.pool,
-                                       registry=self.manager.prefix_registry)
+        inst = self.manager.fork_start(instance_id, arch_key,
+                                       shared_paths=shared_paths)
+        if inst is not None and inst.kv is None:
+            inst.kv = PagedKVCache(instance_id, inst.cfg, self.manager.pool,
+                                   registry=self.manager.prefix_registry)
         return inst
 
     def precompile_prefill(self, inst: ModelInstance) -> None:
@@ -237,6 +321,22 @@ class ServingEngine:
             fn = maker(inst.cfg, self.window)
             inst.compiled[key] = fn
         return fn
+
+    @contextmanager
+    def _dispatch(self, batch: _Batch, name: str, resps,
+                  ids: Dict[str, object]):
+        """One compiled step, from building its params to the program's
+        first read of its result: the span ``name``, and the steps in
+        flight in the process when it started, itself included."""
+        with self._inflight_lock:
+            self._inflight += 1
+            batch.inflight.append(self._inflight)
+        try:
+            with span(name, resps, **ids):
+                yield
+        finally:
+            with self._inflight_lock:
+                self._inflight -= 1
 
     # ------------------------------------------------------------ weights
     def _static_weight_keys(self, inst: ModelInstance,
@@ -416,11 +516,36 @@ class ServingEngine:
         """Continuous-batched execution of requests on one instance:
         per-request prefill, then a joint decode loop that sessions leave
         as they finish."""
-        with self.instance_lock(instance_id):
-            return self._serve_batch_locked(instance_id, reqs)
+        bid = next(self._batch_ids)
+        batch = _Batch(instance_id, bid, [Response(r, batch=bid)
+                                          for r in reqs])
+        outer = getattr(_open, "batch", None)
+        _open.batch = batch
+        try:
+            lock = self.instance_lock(instance_id)
+            with span("serve.lock_wait", batch.resps, **batch.ids()):
+                lock.acquire()
+            try:
+                return self._serve_batch_locked(batch, reqs)
+            finally:
+                lock.release()
+        finally:
+            _open.batch = outer
 
-    def _serve_batch_locked(self, instance_id: str,
+    def _wake(self, batch: _Batch, priority: str):
+        """The request trigger's wake, with its read and install stages
+        (summed over the wake's threads) charged to the batch."""
+        with span("wake", batch.resps, **batch.ids()):
+            st = self.manager.ensure_awake(batch.tenant, trigger="request",
+                                           priority=priority)
+        if st is not None:
+            charge(batch.resps, "wake.read", st.io_seconds)
+            charge(batch.resps, "wake.install", st.inflate_seconds)
+        return st
+
+    def _serve_batch_locked(self, batch: _Batch,
                             reqs: List[Request]) -> List[Response]:
+        instance_id, resps = batch.tenant, batch.resps
         inst = self.manager.instances.get(instance_id)
         # in-flight-request handoff: a request landing on a MIGRATING
         # tenant blocks on the transfer handle (exactly like late wake
@@ -446,7 +571,8 @@ class ServingEngine:
                 raise TenantMigrated(instance_id,
                                      self.manager.migrated[instance_id])
             raise KeyError(f"instance {instance_id} not started")
-        resps = [Response(r, state_before=inst.state.value) for r in reqs]
+        for r in resps:
+            r.state_before = inst.state.value
         t0 = time.monotonic()
 
         # SLO feeds the wake pipeline's priority: an all-batch claim
@@ -462,17 +588,13 @@ class ServingEngine:
                 # wake-storm guard: at most one batched inflate per cycle.
                 # A PARTIAL wake is rung-aware: the critical prefix is
                 # already resident, the cold tail restores behind us.
-                wake_stats = self.manager.ensure_awake(
-                    instance_id, trigger="request",
-                    priority=wake_priority)
+                wake_stats = self._wake(batch, wake_priority)
             inst.sm.fire(Event.REQUEST)       # -> HIBERNATE_RUNNING
             finish_to = S.WOKEN
         elif inst.state in (S.WARM, S.MMAP_CLEAN):
             if inst.state == S.MMAP_CLEAN:
                 # re-map the shared base weights before compute touches them
-                wake_stats = self.manager.ensure_awake(
-                    instance_id, trigger="request",
-                    priority=wake_priority)
+                wake_stats = self._wake(batch, wake_priority)
             inst.sm.fire(Event.REQUEST)       # -> RUNNING
             finish_to = S.WARM
         else:
@@ -494,15 +616,15 @@ class ServingEngine:
             cfg = inst.cfg
             sids = []
             for req, resp in zip(reqs, resps):
-                with self.trace.span("prefill"):
-                    self._prefill_one(inst, req, resp)
+                with span("serve.prefill", (resp,), **batch.ids(req)):
+                    self._prefill_one(inst, req, resp, batch)
                 sids.append(req.session_id)
 
             # ---- joint decode
             active = [i for i, r in enumerate(reqs) if r.max_new_tokens > 0]
             if active:
-                with self.trace.span("decode"):
-                    self._decode_joint(inst, reqs, resps, sids)
+                with span("serve.decode", resps, **batch.ids()):
+                    self._decode_joint(inst, reqs, resps, sids, batch)
         finally:
             if pipe is not None:
                 pipe.backpressure(-1)
@@ -514,31 +636,36 @@ class ServingEngine:
         for req in reqs:
             if req.close_session:
                 inst.kv.close_session(req.session_id)
+        inflight = (sum(batch.inflight) / len(batch.inflight)
+                    if batch.inflight else 0.0)
         for r in resps:
             r.state_after = inst.state.value
+            r.dispatch_inflight = inflight
             r.spans["e2e"] = time.monotonic() - t0
         return resps
 
     # ------------------------------------------------------------ internals
     def _prefill_one(self, inst: ModelInstance, req: Request,
-                     resp: Response) -> None:
+                     resp: Response, batch: _Batch) -> None:
         cfg = inst.cfg
         kv = inst.kv
+        ids, mine = batch.ids(req), (resp,)
         if req.session_id not in kv.sessions:
-            if self._try_adopt_prefix(inst, req, resp):
+            if self._try_adopt_prefix(inst, req, resp, ids):
                 return
             kv.new_session(req.session_id)
         sess = kv.sessions[req.session_id]
 
         # fault statically-known weights + this session's existing cache
-        static_keys = self._static_weight_keys(inst, req.prompt)
-        self._fault(inst, static_keys, resp)
-        inst.recorder.record_many(
-            k for k in static_keys if k[0] == "w")
-        if sess.num_tokens:
-            prior = kv.keys_for(req.session_id, window_tokens=None)
-            self._fault(inst, prior, resp)
-            inst.recorder.record_many(prior)
+        with span("prefill.fault", mine, **ids):
+            static_keys = self._static_weight_keys(inst, req.prompt)
+            self._fault(inst, static_keys, resp)
+            inst.recorder.record_many(
+                k for k in static_keys if k[0] == "w")
+            if sess.num_tokens:
+                prior = kv.keys_for(req.session_id, window_tokens=None)
+                self._fault(inst, prior, resp)
+                inst.recorder.record_many(prior)
 
         tokens = np.asarray(req.prompt, np.int32)[None]    # (1, S)
         Sb = tokens.shape[1]
@@ -552,58 +679,63 @@ class ServingEngine:
         # mid-run, and a post-run residency check would then accept logits
         # computed with zeroed (or torn) weights.  A key missing from the
         # pre-dispatch snapshot always forces one more run.
-        for _ in range(8):
-            snapshot = inst.resident.copy()
-            params = inst.params_pytree()
-            logits, caches, aux = fn(params, jnp.asarray(tokens),
-                                     embeds, frames)
-            ek = self._expert_keys(inst, aux.get("expert_counts"))
-            missing = [k for k in ek if k not in snapshot]
-            inst.recorder.record_many(ek)
-            if not missing:
-                break
-            self._fault(inst, missing, resp)
-        resp.tokens.append(int(jnp.argmax(logits[0, :cfg.vocab_size])))
+        with self._dispatch(batch, "prefill.dispatch", mine, ids):
+            for _ in range(8):
+                snapshot = inst.resident.copy()
+                params = inst.params_pytree()
+                resp.h2d_bytes += _host_bytes(params)
+                logits, caches, aux = fn(params, jnp.asarray(tokens),
+                                         embeds, frames)
+                ek = self._expert_keys(inst, aux.get("expert_counts"))
+                missing = [k for k in ek if k not in snapshot]
+                inst.recorder.record_many(ek)
+                if not missing:
+                    break
+                self._fault(inst, missing, resp)
+            resp.tokens.append(int(jnp.argmax(logits[0, :cfg.vocab_size])))
         # first streamed token: fires as soon as prefill completes, which
         # on a woken tenant is right after the critical prefix landed
         req.emit(resp.tokens[-1])
 
         # write prefill KV into pages
         n0 = sess.num_tokens
-        S_tot = Sb + (0 if req.embeds is None or cfg.is_encoder_decoder
-                      else req.embeds.shape[0])
-        layers = {} if caches is None else \
-            {k: np.asarray(v) for k, v in caches.items()}
-        touched: List[Tuple] = []
-        if kv.token_elems:
-            for l in range(cfg.num_layers):
-                if cfg.attention == "mla":
-                    new = np.concatenate([layers["ckv"][l, 0],
-                                          layers["krope"][l, 0]], -1)
-                else:
-                    new = np.stack([layers["k"][l, 0], layers["v"][l, 0]], 1)
-                touched += kv.write_tokens(
-                    req.session_id, l,
-                    new.reshape(S_tot, kv.token_elems), n0)
-        for kind in ("state", "conv", "cross_k", "cross_v"):
-            if kind in layers:
-                touched.append(kv.set_host_unit(
-                    req.session_id, "all", kind, layers[kind][:, 0]))
-        sess.num_tokens = n0 + S_tot
-        sess.token_ids += [int(t) for t in req.prompt]
-        inst.recorder.record_many(touched)
+        with span("kv.write", mine, **ids):
+            S_tot = Sb + (0 if req.embeds is None or cfg.is_encoder_decoder
+                          else req.embeds.shape[0])
+            layers = {} if caches is None else \
+                {k: np.asarray(v) for k, v in caches.items()}
+            touched: List[Tuple] = []
+            if kv.token_elems:
+                for l in range(cfg.num_layers):
+                    if cfg.attention == "mla":
+                        new = np.concatenate([layers["ckv"][l, 0],
+                                              layers["krope"][l, 0]], -1)
+                    else:
+                        new = np.stack([layers["k"][l, 0],
+                                        layers["v"][l, 0]], 1)
+                    touched += kv.write_tokens(
+                        req.session_id, l,
+                        new.reshape(S_tot, kv.token_elems), n0)
+            for kind in ("state", "conv", "cross_k", "cross_v"):
+                if kind in layers:
+                    touched.append(kv.set_host_unit(
+                        req.session_id, "all", kind, layers[kind][:, 0]))
+            sess.num_tokens = n0 + S_tot
+            sess.token_ids += [int(t) for t in req.prompt]
+            inst.recorder.record_many(touched)
 
         # a fresh prompt that just paid full prefill becomes a shareable
         # prefix: later sessions (any tenant of this arch, any node after
         # migration) COW-adopt these pages instead of recomputing
-        registry = kv.registry
-        if registry is not None and n0 == 0 and inst.arch_key \
-                and req.embeds is None and req.frames is None:
-            registry.register(inst.arch_key, kv, req.session_id,
-                              resp.tokens[-1])
+        with span("prefix.register", mine, **ids):
+            registry = kv.registry
+            if registry is not None and n0 == 0 and inst.arch_key \
+                    and req.embeds is None and req.frames is None:
+                registry.register(inst.arch_key, kv, req.session_id,
+                                  resp.tokens[-1])
 
     def _try_adopt_prefix(self, inst: ModelInstance, req: Request,
-                          resp: Response) -> bool:
+                          resp: Response, ids: Dict[str, object]) -> bool:
         """Cross-tenant prefix adoption: if the prompt's salted token-hash
         is registered, map the existing KV pages by COW refcount and emit
         the recorded first token — no prefill forward pass at all.  Static
@@ -620,9 +752,10 @@ class ServingEngine:
                                 [int(t) for t in req.prompt])
         if entry is None:
             return False
-        static_keys = self._static_weight_keys(inst, req.prompt)
-        self._fault(inst, static_keys, resp)
-        inst.recorder.record_many(k for k in static_keys if k[0] == "w")
+        with span("prefill.fault", (resp,), **ids):
+            static_keys = self._static_weight_keys(inst, req.prompt)
+            self._fault(inst, static_keys, resp)
+            inst.recorder.record_many(k for k in static_keys if k[0] == "w")
         registry.adopt(entry.digest, kv, req.session_id)
         resp.adopted_prefix = True
         resp.tokens.append(entry.first_token)
@@ -631,17 +764,21 @@ class ServingEngine:
         return True
 
     def _decode_joint(self, inst: ModelInstance, reqs: List[Request],
-                      resps: List[Response], sids: List[str]) -> None:
+                      resps: List[Response], sids: List[str],
+                      batch: _Batch) -> None:
         cfg = inst.cfg
         kv = inst.kv
+        ids = batch.ids()
         max_new = max(r.max_new_tokens for r in reqs)
         max_len = _bucket(max(kv.sessions[s].num_tokens for s in sids)
                           + max_new)
         # fault every page the decode window will read
-        for sid in sids:
-            self._fault(inst, kv.keys_for(sid), resps[0])
-            inst.recorder.record_many(kv.keys_for(sid))
-        cache = self._dense_cache(inst, sids, max_len)
+        with span("decode.fault", resps, **ids):
+            for sid in sids:
+                self._fault(inst, kv.keys_for(sid), resps[0])
+                inst.recorder.record_many(kv.keys_for(sid))
+        with span("kv.gather", resps, **ids):
+            cache = self._dense_cache(inst, sids, max_len)
         start_lens = np.asarray(cache["lengths"]).copy()
         B = len(sids)
         fn = self._compiled(inst, "decode", B, max_len, False, False)
@@ -649,43 +786,50 @@ class ServingEngine:
                           jnp.int32)
         done = np.zeros((B,), bool)
         for _step in range(max_new - 1 + 1):
-            # the fed-back tokens' embedding rows page-fault on access
-            ek = self._embed_keys(inst, np.asarray(cur))
-            inst.recorder.record_many(ek)
-            self._fault(inst, ek, resps[0])
-            # page-fault-and-retry on expert residency: re-run the SAME
-            # step from the pre-step cache until every routed expert was
-            # resident in the PRE-dispatch snapshot (see _prefill_one for
-            # why the snapshot must precede the run)
-            for _ in range(4):
-                snapshot = inst.resident.copy()
-                params = inst.params_pytree()
-                logits, new_cache, aux = fn(params, cur, cache)
-                counts = aux.get("expert_counts")
-                if counts is None:
-                    break
-                ek = self._expert_keys(inst, np.asarray(counts))
+            with span("decode.step", resps, **ids):
+                # the fed-back tokens' embedding rows page-fault on access
+                ek = self._embed_keys(inst, np.asarray(cur))
                 inst.recorder.record_many(ek)
-                missing = [k for k in ek if k not in snapshot]
-                if not missing:
-                    break
-                self._fault(inst, missing, resps[0])
-            cache = new_cache
-            nxt = np.asarray(jnp.argmax(
-                logits[:, :cfg.vocab_size], axis=-1), np.int32)
-            for b, r in enumerate(resps):
-                want = r.request.max_new_tokens
-                if not done[b] and len(r.tokens) < want:
-                    r.tokens.append(int(nxt[b]))
-                    r.request.emit(r.tokens[-1])
-                    if len(r.tokens) >= want:
+                self._fault(inst, ek, resps[0])
+                # page-fault-and-retry on expert residency: re-run the SAME
+                # step from the pre-step cache until every routed expert
+                # was resident in the PRE-dispatch snapshot (see
+                # _prefill_one for why the snapshot must precede the run)
+                with self._dispatch(batch, "decode.dispatch", resps, ids):
+                    for _ in range(4):
+                        snapshot = inst.resident.copy()
+                        params = inst.params_pytree()
+                        h2d = _host_bytes(params)
+                        for r in resps:
+                            r.h2d_bytes += h2d
+                        logits, new_cache, aux = fn(params, cur, cache)
+                        counts = aux.get("expert_counts")
+                        if counts is None:
+                            break
+                        ek = self._expert_keys(inst, np.asarray(counts))
+                        inst.recorder.record_many(ek)
+                        missing = [k for k in ek if k not in snapshot]
+                        if not missing:
+                            break
+                        self._fault(inst, missing, resps[0])
+                    cache = new_cache
+                    nxt = np.asarray(jnp.argmax(
+                        logits[:, :cfg.vocab_size], axis=-1), np.int32)
+                for b, r in enumerate(resps):
+                    r.decode_steps += 1
+                    want = r.request.max_new_tokens
+                    if not done[b] and len(r.tokens) < want:
+                        r.tokens.append(int(nxt[b]))
+                        r.request.emit(r.tokens[-1])
+                        if len(r.tokens) >= want:
+                            done[b] = True
+                    else:
                         done[b] = True
-                else:
-                    done[b] = True
-            cur = jnp.asarray(nxt)
+                cur = jnp.asarray(nxt)
             if done.all():
                 break
-        self._writeback(inst, sids, cache, start_lens, resps[0])
+        with span("kv.writeback", resps, **ids):
+            self._writeback(inst, sids, cache, start_lens, resps[0])
 
     # ------------------------------------------------------------ REAP ops
     def record_sample(self, instance_id: str, req: Request) -> frozenset:

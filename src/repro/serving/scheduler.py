@@ -91,8 +91,12 @@ class AsyncPlatform:
         self.policy = policy
         self.arch_of = arch_of
         self.workers = workers
-        #: per-tenant FIFO of (request, future); insertion-ordered dict
-        self.queues: Dict[str, Deque[Tuple[Request, Future]]] = {}
+        #: per-tenant FIFO of (request, future, monotonic time queued);
+        #: insertion-ordered dict
+        self.queues: Dict[str, Deque[Tuple[Request, Future, float]]] = {}
+        #: claimed future -> seconds its request waited in the queue,
+        #: until ``_serve`` puts it on the response as ``serve.queue``
+        self._queued_s: Dict[Future, float] = {}
         self._cv = threading.Condition()
         self._busy: Set[str] = set()          # tenants claimed by a worker
         self._stop = threading.Event()
@@ -192,7 +196,7 @@ class AsyncPlatform:
                     f">= {depth}",
                     retry_after_s=self.retry_after_s(req.instance_id)))
                 return fut
-            q.append((req, fut))
+            q.append((req, fut, time.monotonic()))
             self._note_arrival(req.instance_id, now)
             self._cv.notify()
         if self.policy.predictive_wake:
@@ -215,7 +219,7 @@ class AsyncPlatform:
         with self._cv:
             for q in self.queues.values():
                 while q:
-                    _, fut = q.popleft()
+                    _, fut, _ = q.popleft()
                     if not fut.done():
                         fut.set_exception(exc)
                     failed += 1
@@ -274,10 +278,12 @@ class AsyncPlatform:
     def _claim_tenant(self, iid: str):
         q = self.queues[iid]
         reqs, futs = [], []
+        now = time.monotonic()
         while q:
-            r, f = q.popleft()
+            r, f, queued = q.popleft()
             reqs.append(r)
             futs.append(f)
+            self._queued_s[f] = now - queued
         self._busy.add(iid)
         return iid, reqs, futs
 
@@ -300,6 +306,8 @@ class AsyncPlatform:
 
     def _serve(self, iid: str, reqs: List[Request],
                futs: List[Future]) -> None:
+        with self._cv:
+            queued = [self._queued_s.pop(f, None) for f in futs]
         try:
             mgr = self.engine.manager
             if iid not in mgr.instances and iid not in mgr.migrated:
@@ -316,7 +324,9 @@ class AsyncPlatform:
             resps = self.engine.serve_batch(iid, reqs)
             per_req = (time.monotonic() - t0) / max(len(reqs), 1)
             self._service_ewma += 0.3 * (per_req - self._service_ewma)
-            for f, r in zip(futs, resps):
+            for f, r, q in zip(futs, resps, queued):
+                if q is not None:
+                    r.spans["serve.queue"] = q
                 f.set_result(r)
         except TenantMigrated as e:
             # the tenant lives on another node now: hand the batch to the
