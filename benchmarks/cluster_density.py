@@ -132,8 +132,6 @@ def _run(router, cfg, tenants, horizon, rebalance=True):
             if inst.wake_pipeline is not None:
                 inst.wake_pipeline.wait(60)
             inst.quiesce_bg()
-            if inst.kv is not None:
-                inst.kv.trim()
             inst.last_used = t
     return ttfts, len(sched)
 

@@ -202,7 +202,6 @@ def _run(eng, mgr, schedule, *, measure_from, tick_s=1.0):
         if inst.wake_pipeline is not None:
             inst.wake_pipeline.wait(60)
         inst.quiesce_bg()
-        inst.kv.trim()
         inst.last_used = t
         peak = max(peak, mgr.resident_bytes())
     return {

@@ -117,7 +117,6 @@ def _run(eng, mgr, n, events, policy, seed=7):
         if inst.wake_pipeline is not None:
             inst.wake_pipeline.wait(60)
         inst.quiesce_bg()
-        inst.kv.trim()                 # guest free of the closed session
         inst.last_used = t
         peak = max(peak, mgr.resident_bytes())
         if policy == "always-hib":

@@ -81,14 +81,13 @@ def _run(shared: bool, tenants_per_node: int):
                           PREFIX_PAGES * inst0.kv.page_tokens) \
         .astype(np.int32)
 
-    # jit warm-up on an unrelated prompt of the same shape; close + trim
-    # so its pages (and, sharing-on, its spilled registry entry) don't
-    # count toward the resident measurement
+    # jit warm-up on an unrelated prompt of the same shape; closed so its
+    # pages (and, sharing-on, its spilled registry entry) don't count
+    # toward the resident measurement
     warm = rng.integers(0, cfg.vocab_size, prompt.size).astype(np.int32)
     n0.engine.handle(Request(iids[0], "warm", warm, max_new_tokens=1))
     n0.engine.handle(Request(iids[0], "warm", [3], max_new_tokens=3,
                              close_session=True))
-    inst0.kv.trim()
 
     prefill_ts, adopt_ts, xnode_ts = [], [], []
     transcript = []
@@ -140,7 +139,6 @@ def _run(shared: bool, tenants_per_node: int):
         n1.engine.handle(Request(iid, "warm", w, max_new_tokens=1))
         n1.engine.handle(Request(iid, "warm", [3], max_new_tokens=3,
                                  close_session=True))
-        inst.kv.trim()
         open_sessions(n1, iid, bucket=xnode_ts)
 
     # the migrated tenant's sessions survive the move: decode each

@@ -265,7 +265,6 @@ def _export_bundle(src_node, inst: ModelInstance,
                 "session_id": sid,
                 "num_tokens": s.num_tokens,
                 "token_ids": list(s.token_ids),
-                "closed": s.closed,
                 "page_counts": [len(layer) for layer in s.pages],
                 "host_shapes": dict(s.host_shapes),
                 "host_keys": list(s.host_units),
@@ -373,8 +372,7 @@ def _populate_target(mgr, inst: ModelInstance,
             token_ids=list(sd["token_ids"]),
             pages=[[None] * c for c in sd["page_counts"]],
             host_units={k: None for k in sd["host_keys"]},
-            host_shapes=dict(sd["host_shapes"]),
-            closed=sd["closed"])
+            host_shapes=dict(sd["host_shapes"]))
         digest = sd.get("prefix_digest")
         if reg is not None and digest is not None \
                 and reg.get(digest) is not None:

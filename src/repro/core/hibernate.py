@@ -8,8 +8,10 @@ Full deflate (Warm/Woken/MmapClean/Partial -> Hibernate):
      working-set unit the cancelled stream never delivered is restored from
      the (unmodified) REAP file before it is rewritten — a deflate racing a
      wake can never lose bytes.
-  2. *Reclaim freed memory*: trim KV-cache slack pages back to the shared
-     pool (the Bitmap allocator returns fully-free blocks to the host).
+  2. *Reclaim freed memory*: nothing is left to do here.  A closed
+     session's pages went back to the shared pool when its request ended
+     (:meth:`PagedKVCache.close_session`; the Bitmap allocator returns
+     fully-free blocks to the host).
   3. *Swap out committed memory*: weight units + live KV pages.  Working-set
      units (from the REAP recorder) go to the REAP file with one batched
      sequential write **in first-touch order**; the rest go to the
@@ -69,7 +71,6 @@ class DeflateStats:
     reap_bytes: int = 0
     swap_bytes: int = 0              # logical (raw) bytes sent to swap tier
     kv_pages_swapped: int = 0
-    kv_pages_reclaimed: int = 0
     shared_bytes_released: int = 0
     # content-addressed tier breakdown for a SwapStore-backed instance
     # (a verbatim per-sandbox SwapFile reports stored == swap_bytes)
@@ -150,10 +151,6 @@ class HibernationManager:
         # restore them now or their bytes would be lost
         self._restore_reap_leftovers(inst)
 
-        # step 2: reclaim freed memory — trim KV slack back to the pool
-        if inst.kv is not None:
-            st.kv_pages_reclaimed = inst.kv.trim()
-
         # step 3: swap out committed memory (weights + live KV pages)
         ws = inst.recorder.working_set
         w_reap, w_swap = inst.collect_weight_items(ws)
@@ -183,7 +180,7 @@ class HibernationManager:
         # working set exists — with no recorded set (pagefault-mode
         # deployments) nothing can "miss" it, and hot units must not sink
         # to zlib tiers.  Prune counters for keys that no longer exist
-        # (trimmed sessions) so session churn cannot grow the dict
+        # (closed sessions) so session churn cannot grow the dict
         if ws:
             inst.recorder.note_misses(k for k, _ in w_swap + kv_swap)
             live = set(inst.units)

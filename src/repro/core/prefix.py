@@ -27,8 +27,9 @@ Lifecycle:
     session (never copied, never overwritten: the cache's write path
     breaks COW on refcount > 1, so adopted decode is bit-exact);
   * ``spill`` — last-sharer-down (every sharer deflated or gone): the
-    registry frees its resident references; the pages live on as CAS
-    segments and ``revive`` rebuilds them by digest instead of prefill;
+    registry frees its resident pages and drops its host units; both live
+    on as CAS segments and ``revive`` rebuilds them by digest in one
+    vectored read instead of prefill;
   * migration ships registry *records* (digests + token ids, no page
     payloads): the target rebuilds from its own registry or store.
 """
@@ -59,9 +60,11 @@ class PrefixEntry:
     n_pages: int
     #: pages[layer][i] = pool page id while resident; None while spilled
     pages: Optional[List[List[int]]]
-    #: host units (SSM state, conv, cross-K/V) keyed (layer, kind) — small,
-    #: kept resident even while the pool pages are spilled
+    #: host units (SSM state, conv, cross-K/V) keyed (layer, kind) while
+    #: resident; empty while spilled (the store holds them as ``pfxh`` keys)
     host_units: Dict[Tuple, np.ndarray] = field(default_factory=dict)
+    #: bytes of the host units (stable across spill/revive, like n_pages)
+    host_bytes: int = 0
     #: sessions currently mapping this prefix: (instance_id, session_id)
     sharers: Set[Tuple[str, str]] = field(default_factory=set)
     #: the subset of sharers whose prefix slots are currently resident
@@ -166,7 +169,8 @@ class PrefixRegistry:
                 num_tokens=s.num_tokens,
                 first_token=int(first_token), n_layers=len(pages),
                 n_pages=sum(len(layer) for layer in pages),
-                pages=pages, host_units=host)
+                pages=pages, host_units=host,
+                host_bytes=sum(a.nbytes for a in host.values()))
             self._entries[digest] = e
             self.registrations += 1
             self._write_through(e, kv)
@@ -286,7 +290,7 @@ class PrefixRegistry:
 
     def release_sharer(self, digest: bytes, instance_id: str,
                        session_id: str) -> None:
-        """A sharer is gone for good (session trimmed / closed)."""
+        """A sharer is gone for good (session closed)."""
         with self._lock:
             e = self._entries.get(digest)
             if e is None:
@@ -324,6 +328,7 @@ class PrefixRegistry:
         pages = e.page_ids()
         self.pool.free(pages, PREFIX_OWNER)
         e.pages = None
+        e.host_units = {}          # written through at register
         self.spills += 1
         return len(pages) * self.pool.page_bytes
 
@@ -370,15 +375,18 @@ class PrefixRegistry:
             return True
 
     def _revive(self, e: PrefixEntry) -> None:
-        """Rebuild the resident copy from the CAS store by digest — the
-        whole point of write-through: no prefill, one vectored read."""
+        """Rebuild the resident copy (pages and host units) from the CAS
+        store by digest — the whole point of write-through: no prefill,
+        one vectored read."""
         if self.store is None:
             raise KeyError(("prefix", e.digest, "spilled without a store"))
         client = self.store.client(PREFIX_OWNER)
         keys = sorted((k for k in client.extents
                        if k[0] == "pfx" and k[1] == e.digest),
                       key=lambda k: (k[2], k[3]))
-        data = client.read_units(keys)
+        hkeys = [k for k in client.extents
+                 if k[0] == "pfxh" and k[1] == e.digest]
+        data = client.read_units(keys + hkeys)
         pages: List[List[int]] = [[] for _ in range(e.n_layers)]
         pids, rows = [], []
         for k in keys:
@@ -389,13 +397,13 @@ class PrefixRegistry:
         if pids:
             self.pool.scatter(pids, np.stack(rows))
         e.pages = pages
+        e.host_units = {(k[2], k[3]): data[k] for k in hkeys}
         self.revives += 1
 
     # ------------------------------------------------------------- cluster
     def entry_bytes(self, e: PrefixEntry) -> int:
         """Logical bytes sharing this prefix saves a would-be prefiller."""
-        host = sum(a.nbytes for a in e.host_units.values())
-        return e.n_pages * self.pool.page_bytes + host
+        return e.n_pages * self.pool.page_bytes + e.host_bytes
 
     def inventory(self) -> Dict[bytes, int]:
         """digest -> shareable bytes: what this node advertises to the
@@ -417,6 +425,14 @@ class PrefixRegistry:
         """Physical bytes the registry itself pins (PSS share of its
         refcounted pages) — charged once to the node, never per-sharer."""
         return int(self.pool.pss_bytes(PREFIX_OWNER))
+
+    def held_bytes(self) -> int:
+        """Bytes the registry keeps in memory: its pinned page share
+        (:meth:`resident_bytes`) plus the host units of resident entries."""
+        with self._lock:
+            host = sum(e.host_bytes for e in self._entries.values()
+                       if e.host_units)
+        return self.resident_bytes() + host
 
     # ------------------------------------------------------------- wire
     def export_records(self, instance_id: str):
@@ -445,22 +461,21 @@ class PrefixRegistry:
 
     def install_records(self, records) -> int:
         """Migration target: install shipped prefix entries as spilled
-        (pages revive lazily by digest from the just-adopted CAS
-        extents).  Entries already known locally are kept as-is —
-        that is the cross-node win: nothing re-transfers, nothing
+        (pages and host units revive lazily by digest from the just-
+        adopted CAS extents).  Entries already known locally are kept
+        as-is — that is the cross-node win: nothing re-transfers, nothing
         re-prefills.  Returns entries newly installed."""
         n = 0
         with self._lock:
             for r in records:
                 if r["digest"] in self._entries:
                     continue
-                host: Dict[Tuple, np.ndarray] = {}
+                host_bytes = 0
                 if self.store is not None:
                     client = self.store.client(PREFIX_OWNER)
-                    hkeys = [k for k in client.extents
-                             if k[0] == "pfxh" and k[1] == r["digest"]]
-                    for k, arr in client.read_units(hkeys).items():
-                        host[(k[2], k[3])] = arr
+                    host_bytes = sum(
+                        m.nbytes for k, m in client.extents.items()
+                        if k[0] == "pfxh" and k[1] == r["digest"])
                 self._entries[r["digest"]] = PrefixEntry(
                     digest=r["digest"], arch_key=r["arch"],
                     token_ids=tuple(r["token_ids"]),
@@ -468,7 +483,7 @@ class PrefixRegistry:
                     first_token=int(r["first_token"]),
                     n_layers=int(r["n_layers"]),
                     n_pages=int(r["n_pages"]),
-                    pages=None, host_units=host)
+                    pages=None, host_bytes=host_bytes)
                 n += 1
         return n
 

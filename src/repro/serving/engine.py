@@ -34,7 +34,9 @@ Tracing: every layer boundary of a batch is a
 own breakdown in ``Response.spans`` and the profiler sees the same spans
 with ``tenant``/``batch`` (and per request ``session``) stats.  The
 counters beside them (``h2d_bytes``, ``decode_steps``,
-``dispatch_inflight``, ``compiles``) are charged at the same boundaries.
+``dispatch_inflight``, ``compiles``) are charged at the same boundaries;
+``kv_held_bytes`` is read once per batch, after its closed sessions were
+freed.
 """
 from __future__ import annotations
 
@@ -146,6 +148,9 @@ class Response:
     dispatch_inflight: float = 0.0
     #: backend compiles on the serving thread while the batch ran (batch)
     compiles: int = 0
+    #: bytes the tenant's cache and the node's prefix registry still hold
+    #: once the batch finished and its closed sessions were freed (batch)
+    kv_held_bytes: int = 0
 
 
 @dataclass
@@ -633,14 +638,22 @@ class ServingEngine:
         inst.sm.fire(Event.FINISH)
         assert inst.state == finish_to
         inst.last_used = time.monotonic()
-        for req in reqs:
-            if req.close_session:
-                inst.kv.close_session(req.session_id)
+        # a closed session is freed now, not at the next deflate, so a
+        # tenant kept warm holds only its open sessions.  Under the install
+        # lock: a wake stream or lookahead install may be writing the cache
+        with inst.install_lock:
+            for req in reqs:
+                if req.close_session:
+                    inst.kv.close_session(req.session_id)
+        held = inst.kv_bytes()
+        if inst.kv.registry is not None:
+            held += inst.kv.registry.held_bytes()
         inflight = (sum(batch.inflight) / len(batch.inflight)
                     if batch.inflight else 0.0)
         for r in resps:
             r.state_after = inst.state.value
             r.dispatch_inflight = inflight
+            r.kv_held_bytes = held
             r.spans["e2e"] = time.monotonic() - t0
         return resps
 

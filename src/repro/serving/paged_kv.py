@@ -11,9 +11,9 @@ exactly like madvise'd memory being recommitted by the host on fault):
   ``("kvh", session_id, layer, kind)``      a host unit (ssm state, conv,
                                             cross_k/v, MLA latent uses "kv")
 
-Sessions model multi-turn serverless invocations: a *closed* session's pages
-are "freed by the guest application but not yet returned to the host" — the
-``trim()`` pass (deflation step 2) returns them to the shared pool.
+Sessions model multi-turn serverless invocations.  A session is freed as
+soon as it is closed: its pages go back to the shared pool and its host
+units are dropped, so a tenant kept warm holds only its open sessions.
 """
 from __future__ import annotations
 
@@ -33,7 +33,6 @@ class KVSession:
     #: host units: key -> array (None while swapped out)
     host_units: Dict[Tuple, Optional[np.ndarray]] = field(default_factory=dict)
     host_shapes: Dict[Tuple, Tuple] = field(default_factory=dict)
-    closed: bool = False
     #: registry digest when the leading tokens map a shared prefix
     #: (:mod:`repro.core.prefix`); stable across hibernation cycles
     prefix_digest: Optional[bytes] = None
@@ -77,8 +76,16 @@ class PagedKVCache:
         return s
 
     def close_session(self, session_id: str) -> None:
-        """Guest 'free': pages stay committed until trim() reclaims them."""
-        self.sessions[session_id].closed = True
+        """Free a session its last request is done with: its pages return
+        to the pool, its host units are dropped, and the prefix registry
+        loses a sharer (the last one down spills the prefix).  An install
+        that arrives for it later finds no session and is skipped."""
+        s = self.sessions.pop(session_id)
+        self.pool.free([p for layer in s.pages for p in layer
+                        if p is not None], self.instance_id)
+        if self.registry is not None and s.prefix_digest is not None:
+            self.registry.release_sharer(s.prefix_digest, self.instance_id,
+                                         session_id)
 
     def fork_session(self, src_id: str, dst_id: str) -> KVSession:
         """COW prefix sharing: the new session references the same physical
@@ -274,19 +281,6 @@ class PagedKVCache:
         return s.pages[layer][pidx]
 
     # ------------------------------------------------------------- hibernate
-    def trim(self) -> int:
-        """Deflation step 2: return closed sessions' pages to the pool."""
-        n = 0
-        for sid in [s for s, v in self.sessions.items() if v.closed]:
-            s = self.sessions.pop(sid)
-            pages = [p for layer in s.pages for p in layer if p is not None]
-            n += len(pages)
-            self.pool.free(pages, self.instance_id)
-            if self.registry is not None and s.prefix_digest is not None:
-                self.registry.release_sharer(s.prefix_digest,
-                                             self.instance_id, sid)
-        return n
-
     def _export_page(self, s: KVSession, pid: int, pidx: int) -> np.ndarray:
         """One page's swap-out copy.  The region beyond its written
         tokens is allocator garbage; it is zeroed so identical-content
@@ -457,8 +451,8 @@ class PagedKVCache:
         slots are still Not-Present) and written with a single
         :meth:`PagePool.scatter` — the ``page_copy.scatter_pages`` path,
         one scatter per wake-pipeline chunk instead of a per-page
-        ``_set`` copy.  Host units install individually.  Keys of closed/
-        trimmed sessions are skipped (a streamed wake may outlive them),
+        ``_set`` copy.  Host units install individually.  Keys of closed
+        sessions are skipped (a streamed wake may outlive them),
         and so are keys that are ALREADY resident: concurrent installers
         (streamer / demand / lookahead) are idempotent, and a stale
         background install must never clobber a page the engine has since

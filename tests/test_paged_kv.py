@@ -1,4 +1,4 @@
-"""Paged KV cache: sessions, COW forks, trim, swap/fault cycle."""
+"""Paged KV cache: sessions, COW forks, close, swap/fault cycle."""
 import numpy as np
 import pytest
 
@@ -48,20 +48,25 @@ def test_fork_cow_shares_pages(cache):
     np.testing.assert_allclose(kv.read_tokens("t", 0, 20), data)
     # refcounts: freeing the original keeps the fork readable
     kv.close_session("s")
-    kv.trim()
     np.testing.assert_allclose(kv.read_tokens("t", 0, 20), data)
 
 
 def test_trim_reclaims_closed_sessions(cache):
+    """Closing a session returns its pages to the pool and drops its host
+    units at once; an install that arrives for it later is skipped."""
     kv, cfg, pool = cache
     kv.new_session("s")
     for l in range(cfg.num_layers):
         kv.write_tokens("s", l, _rand_kv(kv, 16), 0)
-    used = pool.used_bytes
-    assert used > 0
+    kv.set_host_unit("s", "all", "state", np.ones((2, 3), np.float32))
+    assert pool.used_bytes > 0 and kv.host_bytes() > 0
     kv.close_session("s")
-    assert pool.used_bytes == used            # guest-freed, not yet returned
-    assert kv.trim() > 0                      # deflation step 2
+    assert pool.used_bytes == 0 and kv.host_bytes() == 0
+    assert "s" not in kv.sessions
+    late = [(("kv", "s", 0, 0), np.zeros(pool.page_elems, np.float32)),
+            (("kvh", "s", "all", "state"), np.ones((2, 3), np.float32))]
+    assert kv.install_batch(late) == 0
+    assert kv.nonresident_keys([k for k, _ in late]) == []
     assert pool.used_bytes == 0
 
 

@@ -136,11 +136,10 @@ def test_refcounts_balance_after_close_and_trim(eng):
     for sid in ("s0", "s1"):
         eng.handle(Request("t0", sid, [3], max_new_tokens=1,
                            close_session=True))
-    inst.kv.trim()
     # last sharer down: the entry spilled to the CAS tier
     st = mgr.prefix_registry.stats()
     assert st["entries"] == 1 and st["resident_entries"] == 0
-    assert mgr.pool.rss_bytes("t0") == 0
+    assert mgr.pool.rss_bytes("t0") == 0 and not inst.kv.sessions
     assert mgr.pool.pss_bytes(PREFIX_OWNER) == 0
 
 
@@ -148,10 +147,8 @@ def test_spill_and_revive_by_digest(eng):
     eng, mgr = eng
     eng.start_instance("t0", ARCH)
     r1 = _prefill(eng, "t0", "s0")
-    inst = mgr.instances["t0"]
     eng.handle(Request("t0", "s0", [3], max_new_tokens=1,
                        close_session=True))
-    inst.kv.trim()
     reg = mgr.prefix_registry
     assert reg.stats()["resident_entries"] == 0
     r2 = _prefill(eng, "t0", "sNew")         # revives from CAS, no prefill
